@@ -37,8 +37,8 @@ from repro.analysis.jaxpr.capture import (
 
 #: primitives that round-trip through the host when executed
 CALLBACK_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "outside_call",
-    "host_callback_call", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "outside_call", "host_callback_call", "callback",
 }
 
 #: default J4 threshold — bigger than any legitimate small table

@@ -1,12 +1,11 @@
-"""Single home for version-gated jax imports.
+"""Single home for the jax symbols whose location has moved between
+releases.
 
-jax's public surface moved between 0.4.x and 0.5+ (most visibly
-``jax.sharding.AxisType`` and the ``axis_types=`` kwarg on
-``jax.make_mesh``).  Every module in this repo that needs a symbol whose
-location or existence depends on the jax version imports it from here, so
-the next jax bump is a one-file change.
+Every module in this repo that needs ``AxisType``, ``shard_map``,
+``axis_size`` or mesh construction imports it from here, so the next jax
+bump is a one-file change.
 
-Supported: jax >= 0.4.30 (tested on 0.4.37) and jax >= 0.5.
+Supported: jax 0.9.0.
 """
 from __future__ import annotations
 
@@ -15,7 +14,7 @@ import jax
 # Stable across all supported versions — re-exported so callers never
 # import from jax.sharding directly.
 # repro-lint: disable=R8 -- re-export surface: parallel/*, core.rao, launch.dryrun import these from here
-from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec  # noqa: F401
 
 
 def _version_tuple(v: str):
@@ -28,63 +27,27 @@ def _version_tuple(v: str):
 
 JAX_VERSION = _version_tuple(jax.__version__)
 
-try:  # jax >= 0.5: meshes carry explicit per-axis types
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    HAS_AXIS_TYPE = True
-except ImportError:  # jax < 0.5: every axis is implicitly "auto"
-    class AxisType:  # minimal stand-in so annotations/defaults still work
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    HAS_AXIS_TYPE = False
-
-
-try:  # jax >= 0.5: shard_map is a public top-level API
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = ("check_vma"
-             if "check_vma" in _inspect.signature(_shard_map).parameters
-             else "check_rep")
+#: meshes carry explicit per-axis types
+HAS_AXIS_TYPE = True
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``: top-level on jax >= 0.5, experimental
-    before; translates ``check_vma`` to the older ``check_rep`` spelling."""
-    kwargs = {}
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (jax >= 0.6); statically-folded psum before."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of the named mesh axis inside ``shard_map``."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(shape, axes, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates jax without ``axis_types``.
-
-    On jax >= 0.5 the requested (or all-Auto default) axis types are passed
-    through; on jax < 0.5 they are dropped — which is behavior-preserving,
-    since pre-0.5 meshes are implicitly fully automatic.
-    """
-    shape = tuple(shape)
+    """``jax.make_mesh`` with every axis ``Auto`` unless ``axis_types``
+    says otherwise."""
     axes = tuple(axes)
-    if not hasattr(jax, "make_mesh"):  # jax < 0.4.35
-        from jax.experimental import mesh_utils
-        devs = mesh_utils.create_device_mesh(shape, devices=devices)
-        return Mesh(devs, axes)
-    if HAS_AXIS_TYPE:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=tuple(axis_types),
-                             devices=devices)
-    return jax.make_mesh(shape, axes, devices=devices)
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), axes, axis_types=tuple(axis_types),
+                         devices=devices)

@@ -65,7 +65,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                     backend: str | None = None):
     """Single-token decode over a block-table-indexed KV pool (GQA).
 
-    q: (B,H,hd); k_pages/v_pages: (P,bt,K,hd); block_tables: (B,nb) int32;
+    q: (B,H,hd); k_pages/v_pages: (P,K,bt,hd); block_tables: (B,nb) int32;
     seq_lens: (B,) int32; k_new/v_new: (B,K,hd).  See kernels.ref for the
     full contract.  ``backend=None`` -> Pallas kernel on TPU, ref oracle
     elsewhere (the kernel grid would be Python-stepped in interpret mode —
@@ -82,7 +82,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                             backend: str | None = None):
     """Chunked-prefill attention over a partial paged context (GQA).
 
-    q: (B,C,H,hd); k_pages/v_pages: (P,bt,K,hd); block_tables: (B,nb)
+    q: (B,C,H,hd); k_pages/v_pages: (P,K,bt,hd); block_tables: (B,nb)
     int32; ctx_lens: (B,) int32; k_new/v_new: (B,C,K,hd) the chunk's own
     keys/values (folded in causally, written to the pool by the caller
     afterwards).  See kernels.ref for the full contract.  ``backend=None``

@@ -55,7 +55,7 @@ def _kernel(btab_ref, lens_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
     @pl.when(live)
     def _block():
         qb = q_ref[0, 0].astype(jnp.float32).reshape(R, -1)   # (R, hd)
-        kb = kp_ref[0, :, 0].astype(jnp.float32)              # (bt, hd)
+        kb = kp_ref[0, 0].astype(jnp.float32)                 # (bt, hd)
         sc = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (R, bt)
@@ -72,7 +72,7 @@ def _kernel(btab_ref, lens_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        vb = vp_ref[0, :, 0].astype(jnp.float32)              # (bt, hd)
+        vb = vp_ref[0, 0].astype(jnp.float32)                 # (bt, hd)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + \
             jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -112,13 +112,13 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                             interpret: bool = True):
     """Contract of ``kernels.ref.paged_prefill_attention`` (the oracle).
 
-    q: (B, C, H, hd); k_pages/v_pages: (P, bt, K, hd); block_tables:
+    q: (B, C, H, hd); k_pages/v_pages: (P, K, bt, hd); block_tables:
     (B, nb) int32 (< 0 = unallocated/released); ctx_lens: (B,) int32 tokens
     resident; k_new/v_new: (B, C, K, hd) the chunk's keys/values.
     Returns (B, C, H, hd).
     """
     B, C, H, hd = q.shape
-    P, bt, K, _ = k_pages.shape
+    P, K, bt, _ = k_pages.shape
     nb = block_tables.shape[1]
     G = H // K
     scale = 1.0 / np.sqrt(hd)
@@ -139,12 +139,12 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                          lambda s, k, b, bt_, ln: (s, k, 0, 0)),
             pl.BlockSpec((1, 1, C, hd),
                          lambda s, k, b, bt_, ln: (s, k, 0, 0)),
-            pl.BlockSpec((1, bt, 1, hd),
+            pl.BlockSpec((1, 1, bt, hd),
                          lambda s, k, b, bt_, ln:
-                         (jnp.maximum(bt_[s, b], 0), 0, k, 0)),
-            pl.BlockSpec((1, bt, 1, hd),
+                         (jnp.maximum(bt_[s, b], 0), k, 0, 0)),
+            pl.BlockSpec((1, 1, bt, hd),
                          lambda s, k, b, bt_, ln:
-                         (jnp.maximum(bt_[s, b], 0), 0, k, 0)),
+                         (jnp.maximum(bt_[s, b], 0), k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, C, G, hd),
                                lambda s, k, b, bt_, ln: (s, k, 0, 0, 0)),

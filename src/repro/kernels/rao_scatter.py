@@ -33,9 +33,8 @@ def _kernel(idx_ref, val_ref, table_ref, o_ref, *, block_m: int, n_rows: int):
 
     def body(i, _):
         row = idx[i]
-        cur = pl.load(o_ref, (pl.dslice(row, 1), slice(None)))
-        pl.store(o_ref, (pl.dslice(row, 1), slice(None)),
-                 cur + vals[i][None].astype(o_ref.dtype))
+        cur = o_ref[pl.ds(row, 1), :]
+        o_ref[pl.ds(row, 1), :] = cur + vals[i][None].astype(o_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, block_m, body, 0)

@@ -32,6 +32,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _gather_pages(pages, block_tables):
+    """(P, K, bt, hd) arena + (B, nb) table -> (B, nb * bt, K, hd) in
+    position order; entries < 0 read page 0 (the caller masks them)."""
+    B, nb = block_tables.shape
+    _, K, bt, hd = pages.shape
+    g = pages[jnp.maximum(block_tables, 0)]              # (B, nb, K, bt, hd)
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * bt, K, hd)
+
+
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                     k_new, v_new, *, window: int = 0,
                     scale: float | None = None):
@@ -39,7 +48,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     KV pool (jit-compatible dense gather; the oracle for the Pallas kernel).
 
     q: (B, H, hd) — one query token per slot, H % K == 0 (GQA).
-    k_pages, v_pages: (P, bt, K, hd) pooled KV arena in ``bt``-token blocks.
+    k_pages, v_pages: (P, K, bt, hd) pooled KV arena in ``bt``-token blocks.
     block_tables: (B, nb) int32 — page ids per slot in position order;
         entries < 0 are unallocated (their positions must be masked dead).
     seq_lens: (B,) int32 — tokens resident in the pages per slot; the query
@@ -50,14 +59,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     Returns (B, H, hd) in q.dtype.
     """
     B, H, hd = q.shape
-    P, bt, K, _ = k_pages.shape
+    P, K, bt, _ = k_pages.shape
     nb = block_tables.shape[1]
     G = H // K
     scale = scale or 1.0 / np.sqrt(hd)
 
-    pages = jnp.maximum(block_tables, 0)                 # (B, nb)
-    kg = k_pages[pages].reshape(B, nb * bt, K, hd)       # gather, pos order
-    vg = v_pages[pages].reshape(B, nb * bt, K, hd)
+    kg = _gather_pages(k_pages, block_tables)            # (B, T, K, hd)
+    vg = _gather_pages(v_pages, block_tables)
     pos = jnp.arange(nb * bt)[None, :]                   # (1, T)
     live = pos < seq_lens[:, None]
     if window:
@@ -85,7 +93,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     Pallas kernel).
 
     q: (B, C, H, hd) — one prompt chunk per slot, H % K == 0 (GQA).
-    k_pages, v_pages: (P, bt, K, hd) pooled KV arena in ``bt``-token blocks.
+    k_pages, v_pages: (P, K, bt, hd) pooled KV arena in ``bt``-token blocks.
     block_tables: (B, nb) int32 — page ids per slot in position order;
         entries < 0 are unallocated/released (masked dead).
     ctx_lens: (B,) int32 — tokens already resident in the pages; chunk
@@ -101,14 +109,13 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     Returns (B, C, H, hd) in q.dtype.
     """
     B, C, H, hd = q.shape
-    P, bt, K, _ = k_pages.shape
+    P, K, bt, _ = k_pages.shape
     nb = block_tables.shape[1]
     G = H // K
     scale = scale or 1.0 / np.sqrt(hd)
 
-    pages = jnp.maximum(block_tables, 0)                 # (B, nb)
-    kg = k_pages[pages].reshape(B, nb * bt, K, hd)       # gather, pos order
-    vg = v_pages[pages].reshape(B, nb * bt, K, hd)
+    kg = _gather_pages(k_pages, block_tables)            # (B, T, K, hd)
+    vg = _gather_pages(v_pages, block_tables)
     pos = jnp.arange(nb * bt)[None, None, :]             # (1, 1, T)
     qpos = (ctx_lens[:, None]
             + jnp.arange(C)[None, :])[:, :, None]        # (B, C, 1)

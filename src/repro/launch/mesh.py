@@ -5,7 +5,7 @@ importing this module never touches jax device state.  The dry-run process
 forces 512 host devices via XLA_FLAGS before any jax import.
 
 Version-gated jax symbols (AxisType, make_mesh kwargs) come from
-``repro.compat`` so this module imports cleanly on jax 0.4.x and 0.5+.
+``repro.compat``.
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
 """Serving launcher: batched requests through the Cohet RPC front-end.
 
 ``python -m repro.launch.serve --arch xlstm-125m --requests 8``
-Spins up the serving engine on a reduced config, submits wire-encoded
+Spins up the serving engine on a reduced config (``--full-config`` for the
+published widths, ``--layers N`` to cut depth), submits wire-encoded
 requests (core.rpc codec — the stage the paper's CXL-NIC offloads), runs
 continuous batching to completion, and reports tokens + scheduler stats
 plus the SimCXL-projected CXL-NIC vs PCIe-NIC host cost of the run.
@@ -21,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.core import rpc as wire
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 from repro.runtime.loadgen import ARRIVAL_PATTERNS, make_trace, run_closed_loop
 from repro.runtime.server import (
@@ -39,6 +41,12 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the published widths instead of the "
+                         "reduced smoke config")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to its first N layers (depth "
+                         "only; widths stay as configured)")
     ap.add_argument("--arrival", default="all-at-once",
                     choices=ARRIVAL_PATTERNS,
                     help="all-at-once = sync drain; poisson/bursty drive "
@@ -145,8 +153,22 @@ def main(argv=None):
         ap.error("--prefill-slots requires --disagg")
     if args.prefill_slots is not None and args.prefill_slots < 1:
         ap.error(f"--prefill-slots must be >= 1, got {args.prefill_slots}")
+    if args.layers is not None and args.layers < 1:
+        ap.error(f"--layers must be >= 1, got {args.layers}")
 
-    cfg = reduced(get_config(args.arch))
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full_config:
+        cfg = reduced(cfg)
+    if args.layers is not None:
+        if args.layers > cfg.n_layers:
+            ap.error(f"--layers {args.layers} exceeds {cfg.name}'s "
+                     f"{cfg.n_layers} layers")
+        print(f"[serve] {cfg.name}: depth cut {cfg.n_layers} -> "
+              f"{args.layers} layers (d_model {cfg.d_model}, "
+              f"{cfg.n_heads} heads, {cfg.n_kv_heads} kv heads, "
+              f"d_ff {cfg.d_ff}, vocab {cfg.vocab})")
+        cfg = cfg.replace(n_layers=args.layers)
     if cfg.family == "moe":
         # serving default: dropless routing, so moe joins the chunked
         # bucketed prefill pipeline; --moe-routing capacity restores the

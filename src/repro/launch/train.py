@@ -15,6 +15,7 @@ import jax
 
 from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig, ShardedLoader, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 from repro.runtime.trainer import (
     TrainLoopConfig, make_train_step, train_loop,
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = reduced(cfg).replace(grad_accum=1)

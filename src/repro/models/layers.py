@@ -7,6 +7,7 @@ functions over these param pytrees — no framework dependency.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -47,6 +48,13 @@ def logical_axes(schema) -> Any:
                         is_leaf=lambda x: isinstance(x, ParamDef))
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
+def _normal_leaf(key, shape, scale, dtype):
+    # one fused program: the float32 draw never materialises, so a stacked
+    # leaf costs only its ``dtype`` bytes on the device
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
 def init_params(schema, key, dtype) -> Any:
     """Deterministic per-leaf init keyed by tree path.  The path salt is
     crc32, NOT Python's hash(): hash() is randomized per process
@@ -69,7 +77,8 @@ def init_params(schema, key, dtype) -> Any:
         else:
             fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
             scale = p.scale if p.scale is not None else 1.0 / np.sqrt(max(fan_in, 1))
-            arr = (jax.random.normal(sub, p.shape, jnp.float32) * scale).astype(dtype)
+            arr = _normal_leaf(sub, tuple(p.shape), float(scale),
+                               jnp.dtype(dtype))
         out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -362,7 +371,7 @@ def paged_attn_apply(p, x, cfg, k_pages, v_pages, block_tables, seq_lens,
     """Single-token decode attention against a block-table-indexed KV pool.
 
     x: (B, 1, D) — the current token's hidden state per slot;
-    k_pages/v_pages: (P, bt, K, hd) pooled arena (one layer's pages);
+    k_pages/v_pages: (P, K, bt, hd) pooled arena (one layer's pages);
     block_tables: (B, nb) int32; seq_lens: (B,) int32 tokens resident.
     The current token's k/v are projected here, folded into the softmax by
     the kernel, and returned (cast to the pool dtype) for the caller to
@@ -408,7 +417,7 @@ def paged_prefill_attn_apply(p, x, cfg, k_pages, v_pages, block_tables,
     KV pool.
 
     x: (B, C, D) — one prompt chunk per slot, sitting at absolute positions
-    ``ctx_lens + [0, C)``; k_pages/v_pages: (P, bt, K, hd) pooled arena
+    ``ctx_lens + [0, C)``; k_pages/v_pages: (P, K, bt, hd) pooled arena
     (one layer's pages) holding the ``ctx_lens`` tokens of earlier chunks.
     The chunk's own k/v are projected here, folded into the softmax by the
     kernel with the in-chunk causal mask, and returned (cast to the pool

@@ -34,7 +34,7 @@ class Model:
     # paged-KV data plane (block-table-indexed pool); None for families
     # without a uniform KV stack (ssm / hybrid / audio)
     init_paged_cache: Optional[Callable] = None
-    # (batch, max_len, block_tokens) -> pages {"kp","vp"} (L,P,bt,K,hd)
+    # (batch, max_len, block_tokens) -> pages {"kp","vp"} (L,P,K,bt,hd)
     paged_decode_step: Optional[Callable] = None
     # (params, pages, tokens, block_tables, seq_lens, mesh) -> (logits, pages)
     paged_prefill_write: Optional[Callable] = None

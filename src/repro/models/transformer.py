@@ -475,8 +475,9 @@ def paged_blocks(max_len: int, block_tokens: int) -> int:
 
 def lm_init_paged_cache(cfg, batch: int, max_len: int,
                         block_tokens: int = 16, dtype=None, frames=None):
-    """Pooled KV arena: (L, P, bt, K, hd) pages shared by all slots through
-    a block table.  P = batch * max_blocks real pages + one trash page
+    """Pooled KV arena: (L, P, K, bt, hd) pages shared by all slots through
+    a block table.  Each (page, kv-head) block is a (bt, hd) tile — the two
+    minor dimensions the TPU kernels' BlockSpecs address whole.  P = batch * max_blocks real pages + one trash page
     (index P-1) that soaks up writes from inactive slots.  The block table
     and per-slot lengths live host-side (runtime.scheduler.KVBlockPager)
     and ride into each decode step as arguments — the arena is the only
@@ -493,14 +494,14 @@ def lm_init_paged_cache(cfg, batch: int, max_len: int,
     real = frames if frames is not None \
         else batch * paged_blocks(max_len, block_tokens)
     P = real + 1
-    shape = (cfg.n_layers, P, block_tokens, K, hd)
+    shape = (cfg.n_layers, P, K, block_tokens, hd)
     return {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
 
 
 def lm_kv_migrate(near, far, dem_src, dem_dst, pro_src, pro_dst):
     """One fused near<->far migration event over two KV arenas.
 
-    near/far: {"kp", "vp"} arenas (L, P_near/P_far, bt, K, hd);
+    near/far: {"kp", "vp"} arenas (L, P_near/P_far, K, bt, hd);
     dem_src/dem_dst: (D,) int32 — demotions copy near frame dem_src[i]
     into far frame dem_dst[i]; pro_src/pro_dst: (U,) int32 — promotions
     copy far frame pro_src[i] into near frame pro_dst[i].  Pad ragged
@@ -542,7 +543,7 @@ def lm_paged_prefill_write(cfg, pages, k_rows, v_rows, block_ids,
     co-resident readers.  ``block_ids`` then covers only the tail blocks.
     """
     L, G, T, K, hd = k_rows.shape
-    bt = pages["kp"].shape[2]
+    bt = pages["kp"].shape[3]
     nb = block_ids.shape[0] // G
     S = prompt_len
     W = cfg.sliding_window
@@ -569,8 +570,10 @@ def lm_paged_prefill_write(cfg, pages, k_rows, v_rows, block_ids,
         v_rows = jnp.zeros((L, G, S, K, hd),
                            v_rows.dtype).at[:, :, S - T:].set(tail_v)
     pad = ((0, 0), (0, 0), (0, nb * bt - S), (0, 0), (0, 0))
-    k_rows = jnp.pad(k_rows, pad).reshape(L, G * nb, bt, K, hd)
-    v_rows = jnp.pad(v_rows, pad).reshape(L, G * nb, bt, K, hd)
+    k_rows = jnp.pad(k_rows, pad).reshape(L, G * nb, bt, K, hd) \
+        .swapaxes(2, 3)
+    v_rows = jnp.pad(v_rows, pad).reshape(L, G * nb, bt, K, hd) \
+        .swapaxes(2, 3)
     kp = pages["kp"].at[:, block_ids].set(k_rows.astype(pages["kp"].dtype))
     vp = pages["vp"].at[:, block_ids].set(v_rows.astype(pages["vp"].dtype))
     return {"kp": kp, "vp": vp}
@@ -584,7 +587,7 @@ def lm_paged_prefill_chunk(params, cfg, pages, tokens, block_tables,
     sitting at absolute positions [ctx_lens[b], ctx_lens[b] + valid);
     columns past ``valid`` are padding: they compute (finite, self-attended)
     but their KV routes to the trash page and their activations are never
-    read.  pages: {"kp", "vp"} (L, P, bt, K, hd); block_tables: (B, nb)
+    read.  pages: {"kp", "vp"} (L, P, K, bt, hd); block_tables: (B, nb)
     int32 — must cover ``ctx_lens + valid_lens`` tokens for slots in this
     chunk step; rows of slots *not* prefilling this step are < 0 (their
     writes all land on the trash page).  Returns (logits (B, V) at each
@@ -628,15 +631,16 @@ def lm_paged_prefill_chunk(params, cfg, pages, tokens, block_tables,
 
     # one fused scatter of all layers' chunk KV into the donated arena;
     # padding columns (and slots whose table row is masked) -> trash page
-    P, bt = pages["kp"].shape[1], pages["kp"].shape[2]
+    P, bt = pages["kp"].shape[1], pages["kp"].shape[3]
     nb = block_tables.shape[1]
     blk = jnp.clip(positions // bt, 0, nb - 1)
     page_w = jnp.take_along_axis(block_tables, blk, axis=1)  # (B, C)
     valid = jnp.arange(C)[None, :] < valid_lens[:, None]
     page_w = jnp.where(valid & (page_w >= 0), page_w, P - 1)
     off = positions % bt
-    kp = pages["kp"].at[:, page_w, off].set(kns)
-    vp = pages["vp"].at[:, page_w, off].set(vns)
+    # the split (page, offset) index puts its (B, C) dims first
+    kp = pages["kp"].at[:, page_w, :, off].set(jnp.moveaxis(kns, 0, 2))
+    vp = pages["vp"].at[:, page_w, :, off].set(jnp.moveaxis(vns, 0, 2))
 
     # logits at each slot's last valid position (the first generated token
     # when this chunk completes the prompt; ignored otherwise)
@@ -651,7 +655,7 @@ def lm_paged_decode_step(params, cfg, pages, tokens, block_tables, seq_lens,
                          mesh=None):
     """One decode step over the paged KV pool; per-slot ragged lengths.
 
-    tokens: (B, 1) int32; pages: {"kp", "vp"} (L, P, bt, K, hd);
+    tokens: (B, 1) int32; pages: {"kp", "vp"} (L, P, K, bt, hd);
     block_tables: (B, nb) int32 (< 0 = unallocated; nb may be a bucket of
     the full table — it only needs to cover max(seq_lens) + 1 tokens);
     seq_lens: (B,) int32 tokens resident per slot (the new token lands at
@@ -683,14 +687,17 @@ def lm_paged_decode_step(params, cfg, pages, tokens, block_tables, seq_lens,
         cfg.n_layers)
 
     # one fused scatter of all layers' new KV into the donated arena
-    P, bt = pages["kp"].shape[1], pages["kp"].shape[2]
+    P, bt = pages["kp"].shape[1], pages["kp"].shape[3]
     nb = block_tables.shape[1]
     blk = jnp.clip(seq_lens // bt, 0, nb - 1)
     page_w = block_tables[jnp.arange(B), blk]
     page_w = jnp.where(page_w >= 0, page_w, P - 1)   # inactive -> trash page
     off = seq_lens % bt
-    kp = pages["kp"].at[:, page_w, off].set(kns[:, :, 0])
-    vp = pages["vp"].at[:, page_w, off].set(vns[:, :, 0])
+    # the split (page, offset) index puts its (B,) dim first
+    kp = pages["kp"].at[:, page_w, :, off].set(
+        jnp.moveaxis(kns[:, :, 0], 0, 1))
+    vp = pages["vp"].at[:, page_w, :, off].set(
+        jnp.moveaxis(vns[:, :, 0], 0, 1))
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, cfg, x, mesh)[:, 0]

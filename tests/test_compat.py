@@ -1,7 +1,7 @@
 """Regression tests for the version-compat layer (ISSUE 1 bugfixes):
 
 * ``repro.launch.mesh`` imports and builds meshes on the installed jax
-  (0.4.x lacks ``jax.sharding.AxisType`` / ``axis_types=``);
+  (0.9.0);
 * test collection survives without ``hypothesis`` installed (the bundled
   fallback in tests/_hypothesis_fallback.py takes over).
 
@@ -41,8 +41,8 @@ def test_mesh_imports_and_builds_on_installed_jax():
 
 
 def test_compat_is_single_home_for_version_gated_imports():
-    """No module outside repro/compat.py may import the symbols that moved
-    between jax 0.4 and 0.5+ (AxisType, shard_map) straight from jax —
+    """No module outside repro/compat.py may import the symbols that have
+    moved between jax releases (AxisType, shard_map) straight from jax —
     the next jax bump must stay a one-file change."""
     offenders = []
     for dirpath, _, files in os.walk(os.path.join(SRC, "repro")):

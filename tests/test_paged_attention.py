@@ -26,8 +26,8 @@ def _rand_pool(B, H, K, hd, bt, nb, dtype, *, lens):
     ``lens`` tokens per slot (position order; unused entries -1)."""
     P = B * nb + 1
     q = jnp.asarray(RNG.randn(B, H, hd), dtype)
-    kp = jnp.asarray(RNG.randn(P, bt, K, hd), dtype)
-    vp = jnp.asarray(RNG.randn(P, bt, K, hd), dtype)
+    kp = jnp.asarray(RNG.randn(P, K, bt, hd), dtype)
+    vp = jnp.asarray(RNG.randn(P, K, bt, hd), dtype)
     kn = jnp.asarray(RNG.randn(B, K, hd), dtype)
     vn = jnp.asarray(RNG.randn(B, K, hd), dtype)
     perm = RNG.permutation(P - 1)
@@ -82,7 +82,7 @@ def test_paged_ref_matches_dense_gqa():
     vd = jnp.asarray(RNG.randn(B, T + 1, K, hd), jnp.float32)
     q = jnp.asarray(RNG.randn(B, 1, H, hd), jnp.float32)
     P = B * nb + 1
-    kp = np.zeros((P, bt, K, hd), np.float32)
+    kp = np.zeros((P, K, bt, hd), np.float32)
     vp = np.zeros_like(kp)
     btab = np.full((B, nb), -1, np.int32)
     pid = 0
@@ -90,8 +90,8 @@ def test_paged_ref_matches_dense_gqa():
         for i in range(-(-int(lens[b]) // bt)):
             btab[b, i] = pid
             s, e = i * bt, min((i + 1) * bt, int(lens[b]))
-            kp[pid, :e - s] = np.asarray(kd_[b, s:e])
-            vp[pid, :e - s] = np.asarray(vd[b, s:e])
+            kp[pid, :, :e - s] = np.asarray(kd_[b, s:e]).swapaxes(0, 1)
+            vp[pid, :, :e - s] = np.asarray(vd[b, s:e]).swapaxes(0, 1)
             pid += 1
     kn = jnp.stack([kd_[b, int(lens[b])] for b in range(B)])
     vn = jnp.stack([vd[b, int(lens[b])] for b in range(B)])
@@ -114,8 +114,8 @@ def _rand_chunk(B, C, H, K, hd, bt, nb, dtype, *, lens, dead_first=()):
     their leading block released (-1), as partial SWA reclamation does."""
     P = B * nb + 1
     q = jnp.asarray(RNG.randn(B, C, H, hd), dtype)
-    kp = jnp.asarray(RNG.randn(P, bt, K, hd), dtype)
-    vp = jnp.asarray(RNG.randn(P, bt, K, hd), dtype)
+    kp = jnp.asarray(RNG.randn(P, K, bt, hd), dtype)
+    vp = jnp.asarray(RNG.randn(P, K, bt, hd), dtype)
     kn = jnp.asarray(RNG.randn(B, C, K, hd), dtype)
     vn = jnp.asarray(RNG.randn(B, C, K, hd), dtype)
     perm = RNG.permutation(P - 1)
@@ -165,7 +165,7 @@ class TestPagedPrefillKernel:
         vd = jnp.asarray(RNG.randn(B, T + C, K, hd), jnp.float32)
         q = jnp.asarray(RNG.randn(B, C, H, hd), jnp.float32)
         P = B * nb + 1
-        kp = np.zeros((P, bt, K, hd), np.float32)
+        kp = np.zeros((P, K, bt, hd), np.float32)
         vp = np.zeros_like(kp)
         btab = np.full((B, nb), -1, np.int32)
         pid = 0
@@ -173,8 +173,8 @@ class TestPagedPrefillKernel:
             for i in range(-(-int(lens[b]) // bt)):
                 btab[b, i] = pid
                 s, e = i * bt, min((i + 1) * bt, int(lens[b]))
-                kp[pid, :e - s] = np.asarray(kd_[b, s:e])
-                vp[pid, :e - s] = np.asarray(vd[b, s:e])
+                kp[pid, :, :e - s] = np.asarray(kd_[b, s:e]).swapaxes(0, 1)
+                vp[pid, :, :e - s] = np.asarray(vd[b, s:e]).swapaxes(0, 1)
                 pid += 1
         kn = jnp.stack([kd_[b, int(lens[b]):int(lens[b]) + C]
                         for b in range(B)])
@@ -282,7 +282,7 @@ class TestPagedModelVsDense:
             # the new token's kv landed in the right page and matches
             # what the dense cache wrote at the same position
             blk, off = lens[b] // bt, lens[b] % bt
-            got = pages2["kp"][:, btab[b, blk], off].astype(jnp.float32)
+            got = pages2["kp"][:, btab[b, blk], :, off].astype(jnp.float32)
             want = dc2["k"][:, 0, lens[b]].astype(jnp.float32)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=1e-5, rtol=1e-5)

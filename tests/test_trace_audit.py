@@ -74,7 +74,7 @@ def test_j2_debug_print_in_hot_graph_fires():
         f = jax.jit(step)
         f(jnp.ones((4,), F32))
     fs = run_rules(capture(drive))
-    assert any(f.rule == "J2" and "debug_callback" in f.message
+    assert any(f.rule == "J2" and "debug_print" in f.message
                for f in fs)
 
 
