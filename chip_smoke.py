@@ -48,9 +48,10 @@ ARCH = "mistral-nemo-12b"
 LAYERS = 12
 H, K, HD, BT, CHUNK = 32, 8, 128, 16, 64
 REQUESTS, SLOTS, PROMPT, MAX_NEW = 8, 4, 200, 16
-# bf16 outputs of O(1) attention averages: a few bf16 ulps of slack; a
-# kernel that reads the wrong page or mask is off by O(1)
-TOL = 2e-2
+# bf16 outputs of attention averages (|out| up to ~5): one bf16 rounding
+# of either side is up to 2^-8 relative; a kernel that reads the wrong page
+# or mask is off by O(1).  Same bound as the interpret-mode bf16 test.
+ATOL = RTOL = 2e-2
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -76,9 +77,15 @@ def _pool(rng, B, nb, lens, dtype):
     return kp, vp, jnp.asarray(btab), jnp.asarray(lens, jnp.int32)
 
 
-def _max_err(got, want):
-    return float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                 - want.astype(jnp.float32))))
+def _compare(label, got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    err = np.abs(got - want)
+    excess = float(np.max(err - (ATOL + RTOL * np.abs(want))))
+    log(f"{label}: max abs err vs oracle {float(err.max())!r} "
+        f"(bound {ATOL} + {RTOL} * |oracle|, worst margin {-excess!r})")
+    if not np.isfinite(got).all() or excess > 0:
+        raise SystemExit(f"{label}: kernel disagrees with its oracle")
 
 
 def check_kernels():
@@ -99,12 +106,7 @@ def check_kernels():
         with jax.default_matmul_precision("highest"):
             want = jax.jit(ref.paged_attention, static_argnames="window")(
                 q, kp, vp, btab, sl, kn, vn, window=window)
-        err = _max_err(got, want)
-        log(f"paged_attention (decode) window={window}: "
-            f"max abs err vs oracle {err!r} (tol {TOL})")
-        if not np.isfinite(err) or err > TOL:
-            raise SystemExit(f"paged_attention kernel disagrees with its "
-                             f"oracle: {err!r} > {TOL}")
+        _compare(f"paged_attention (decode) window={window}", got, want)
 
     lens = [0, BT, 3 * BT + 5, nb * BT - CHUNK]
     kp, vp, btab, cl = _pool(rng, B, nb, lens, bf)
@@ -118,12 +120,8 @@ def check_kernels():
             want = jax.jit(ref.paged_prefill_attention,
                            static_argnames="window")(
                 q, kp, vp, btab, cl, kn, vn, window=window)
-        err = _max_err(got, want)
-        log(f"paged_prefill_attention (chunk {CHUNK}) window={window}: "
-            f"max abs err vs oracle {err!r} (tol {TOL})")
-        if not np.isfinite(err) or err > TOL:
-            raise SystemExit(f"paged_prefill_attention kernel disagrees "
-                             f"with its oracle: {err!r} > {TOL}")
+        _compare(f"paged_prefill_attention (chunk {CHUNK}) "
+                 f"window={window}", got, want)
 
 
 def check_kernels_in_graph(cfg):
