@@ -10,8 +10,7 @@ updates), and admits per-slot.  Emitted to ``BENCH_decode.json``
 
 * per (context, slots) cell: decode tokens/sec for both engines and the
   paged/dense speedup;
-* admission cost: cache-install (splice vs per-slot page-write) ms/request
-  and total admission (prefill included) ms/request;
+* admission cost: total admission (prefill included) ms/request;
 * methodology record (model, engine capacity, measurement protocol).
 
 Acceptance (full mode): >= 2x decode tokens/sec at 2048-token contexts.
@@ -22,7 +21,7 @@ same request set (``slots`` requests of ``ctx`` prompt tokens, greedy
 decode for ``max_new`` tokens).  A full warmup drain compiles every shape
 first; the measured drain then reads the engine's own step-level counters
 (``decode_wall_s``/``decode_tokens``: jit dispatch + device sync + argmax;
-``splice_wall_s``: cache install, blocked until ready).  CPU timings.
+``admit_wall_s``: admission, prefill included).  CPU timings.
 """
 from __future__ import annotations
 
@@ -75,7 +74,7 @@ def _measure(server, wires, warm_wires):
     wall = time.perf_counter() - t0
     d = {k: server.stats[k] - base[k] for k in
          ("decode_tokens", "decode_wall_s", "decode_steps",
-          "splice_wall_s", "admit_wall_s", "admitted", "completed")}
+          "admit_wall_s", "admitted", "completed")}
     assert d["completed"] == len(wires), "undrained"
     return {
         "decode_tokens": d["decode_tokens"],
@@ -83,8 +82,6 @@ def _measure(server, wires, warm_wires):
         "decode_tokens_per_s": round(d["decode_tokens"]
                                      / max(d["decode_wall_s"], 1e-9), 1),
         "decode_wall_s": round(d["decode_wall_s"], 4),
-        "cache_install_ms_per_req": round(
-            d["splice_wall_s"] / max(d["admitted"], 1) * 1e3, 3),
         "admit_ms_per_req": round(
             d["admit_wall_s"] / max(d["admitted"], 1) * 1e3, 3),
         "wall_s": round(wall, 4),
@@ -102,13 +99,12 @@ def run_cell(model, params, *, ctx: int, slots: int, engine_max: int,
             "max_new": max_new, "prefill_batch": pfb}
     for name, paged in (("dense", False), ("paged", True)):
         # one-shot prefill on both engines: this bench measures the decode
-        # hot path and the admission *install* cost (splice vs page write)
-        # under identical prefill semantics — the chunked pipeline's
-        # trace/TTFT wins are measured by serve_bench's ragged phase
+        # hot path and the admission cost under identical prefill
+        # semantics — the chunked pipeline's trace/TTFT wins are measured
+        # by serve_bench's ragged phase
         srv = BatchServer(model, batch_slots=slots, max_len=engine_max,
                           params=params, nic_cost=None, paged_kv=paged,
-                          prefill_batch=pfb, prefill_chunk=0,
-                          sync_timers=True)
+                          prefill_batch=pfb, prefill_chunk=0)
         # one prefill group warms every jit shape the measured drain hits
         # (decode batch is always `slots`-wide; admission groups are pfb)
         warm = _requests(pfb, ctx, max_new, model.cfg.vocab, seed,
@@ -121,9 +117,6 @@ def run_cell(model, params, *, ctx: int, slots: int, engine_max: int,
     cell["decode_speedup_x"] = round(
         cell["paged"]["decode_tokens_per_s"]
         / max(cell["dense"]["decode_tokens_per_s"], 1e-9), 2)
-    cell["cache_install_speedup_x"] = round(
-        cell["dense"]["cache_install_ms_per_req"]
-        / max(cell["paged"]["cache_install_ms_per_req"], 1e-9), 2)
     return cell
 
 
@@ -162,8 +155,7 @@ def main(argv=None):
         print(f"ctx={ctx:5d} slots={slots:3d}: "
               f"dense {cell['dense']['decode_tokens_per_s']:9.1f} tok/s"
               f" | paged {cell['paged']['decode_tokens_per_s']:9.1f}"
-              f" tok/s | {cell['decode_speedup_x']:5.2f}x decode,"
-              f" {cell['cache_install_speedup_x']:7.2f}x install")
+              f" tok/s | {cell['decode_speedup_x']:5.2f}x decode")
 
     top_ctx = max(contexts)
     top = [c for c in cells if c["ctx"] == top_ctx]
@@ -180,8 +172,8 @@ def main(argv=None):
                         "measured drain of `slots` requests of `ctx` prompt "
                         "tokens, greedy `max_new`; decode tok/s from the "
                         "engine's step counters (jit dispatch + sync + "
-                        "argmax); cache-install from the blocked splice / "
-                        "page-write timer; CPU timings",
+                        "argmax); admission from the admit span; CPU "
+                        "timings",
             "baseline": "PR-2 dense engine (paged_kv=False): shared-write-"
                         "index (slots, max_len) cache, admission splice, "
                         "equal-length admission waves",

@@ -4,8 +4,8 @@ A device->host materialization (``np.asarray`` on a traced output,
 ``.item()``, ``float()``, ``jax.block_until_ready``) inside the tick
 loop serializes the async engine's dispatch overlap: every tick waits
 for the device instead of queueing the next step.  The server keeps a
-small set of *intentional* sync points (the argmax that feeds sampled
-tokens back into Python; the ``sync_timers`` benchmark mode) — those
+small set of *intentional* sync points (the wait for the logits and the
+argmax that feeds sampled tokens back into Python) — those
 carry inline ``# repro-lint: disable=R4 -- reason`` suppressions, which
 is this rule's explicit allowlist.
 

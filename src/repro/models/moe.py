@@ -88,9 +88,14 @@ def _n_groups(cfg, T: int, mesh) -> int:
     return g if T % g == 0 else 1
 
 
+@jax.named_scope("moe_ffn")
 def moe_apply(p, x, cfg, return_aux: bool = False, mesh=None,
               n_groups: int = 0):
     """x: (B, S, D) -> (B, S, D) [, aux losses dict].
+
+    The whole layer (router, dispatch, expert matmuls, combine) runs under
+    the name scope ``moe_ffn``, so its ops carry it in their HLO metadata
+    and a device trace can add up the expert FFN's time.
 
     ``cfg.moe_routing == "dropless"`` makes the layer a pure per-token
     function (capacity can never bind): the output for token t is exactly

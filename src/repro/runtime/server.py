@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import math
 import time
 from collections import deque
@@ -63,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import rpc as wire
+from repro.runtime import spans
 from repro.runtime.niccost import NicCostModel, NullNicCostModel
 from repro.runtime.scheduler import (
     AdmissionQueue, KVBlockPager, Request, RequestState, SlotTable,
@@ -137,6 +139,16 @@ def _prefill_buckets(chunk: int, n_buckets: int):
     return tuple(sorted(sizes))
 
 
+def _named(f, name: str):
+    """``f`` under ``name``: ``jax.jit`` names the program it compiles
+    after the function (``jit_<name>``)."""
+    @functools.wraps(f)
+    def named(*args, **kwargs):
+        return f(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def _splice_rows_tree(cache, cache1, slot_arr, *, n_slots: int):
     """Write a B=k prefill cache into batch rows `slot_arr` of the shared
     cache.  Stacked (L, B, ...) leaves splice on axis 1, per-batch
@@ -172,7 +184,7 @@ class BatchServer:
                  nic_cost: Optional[object] = True, pool=None,
                  jit: bool = True, prefill_batch: int = 1,
                  paged_kv="auto", prefill_chunk="auto",
-                 prefill_buckets: int = 4, sync_timers: bool = False,
+                 prefill_buckets: int = 4,
                  prefix_cache: bool = False, prefix_watermark: float = 0.0,
                  kv_overcommit: float = 1.0,
                  kv_near_blocks: Optional[int] = None,
@@ -371,11 +383,11 @@ class BatchServer:
         # engine's graph set through jit_fns()/trace_counts() instead of
         # poking private attributes
         self._jit_fns: Dict[str, Any] = {}
-        maybe_jit = (lambda f, **kw: jax.jit(f, **kw)) if jit \
-            else (lambda f, **kw: f)
 
         def _jit(name, f, **kw):
-            fn = maybe_jit(f, **kw)
+            # compiled under its registry name (``jit_paged_decode``, not
+            # ``jit__lambda``), which names the program in a device trace
+            fn = jax.jit(_named(f, name), **kw) if jit else f
             self._jit_fns[name] = fn
             return fn
 
@@ -445,14 +457,10 @@ class BatchServer:
         self._tier_dirty = True
         self._engaged_cache: Optional[Set[int]] = None
         self.prefill_batch = max(1, prefill_batch)
-        # block after each cache install so splice_wall_s attributes it
-        # honestly (benchmarks); off by default — a sync per admission
-        # would serialize the async engine's dispatch overlap
-        self.sync_timers = sync_timers
+        # counters, and the seconds of every host span (runtime.spans)
         self.stats = {"prefills": 0, "prefill_chunks": 0, "decode_steps": 0,
                       "completed": 0, "failed": 0, "admitted": 0, "ticks": 0,
-                      "decode_tokens": 0, "decode_wall_s": 0.0,
-                      "admit_wall_s": 0.0, "splice_wall_s": 0.0}
+                      "decode_tokens": 0, **spans.zeroed()}
         self.completed_reqs: List[Request] = []
         self._unbilled_tickets = 0
         self._busy_slot_ticks = 0
@@ -467,6 +475,11 @@ class BatchServer:
     def slot_utilization(self) -> float:
         total = self.stats["ticks"] * self.slots
         return self._busy_slot_ticks / total if total else 0.0
+
+    def _span(self, name: str) -> spans.Span:
+        """``with self._span("decode.select"):`` times a stage of the tick
+        into ``stats`` and onto the profiler's host timeline."""
+        return spans.Span(self.stats, name)
 
     # ------------------------------------------------------- audit hooks
     def jit_fns(self) -> Dict[str, Any]:
@@ -492,7 +505,8 @@ class BatchServer:
 
     def submit_wire(self, buf: bytes):
         msg = wire.decode(buf, REQ_SCHEMA)     # single decode on ingress
-        self.niccost.on_ingress(msg)
+        with self._span("niccost"):
+            self.niccost.on_ingress(msg)
         self.submit(self._request_from_msg(msg, len(buf)))
 
     def submit(self, req: Request):
@@ -579,7 +593,6 @@ class BatchServer:
             req.generated.append(int(nxt[row]))
             self._after_prefill(req, t1)
 
-        tw = time.perf_counter()
         if self.paged:
             # ring-packed SWA one-shot rows (S > window) leave zero-KV
             # leading positions: those pages must be neither acquired from
@@ -596,9 +609,10 @@ class BatchServer:
                 skip, ids = self.pager.admit_cached(
                     int(slot_arr[0]), reqs[0].prompt, S)
                 if skip:
-                    self.niccost.on_prefix_share(
-                        skip // self.pager.block_tokens,
-                        self.pager.block_bytes)
+                    with self._span("niccost"):
+                        self.niccost.on_prefix_share(
+                            skip // self.pager.block_tokens,
+                            self.pager.block_bytes)
             else:
                 # one fused write of the admitted slots' blocks; nobody
                 # else's cache moves
@@ -615,9 +629,6 @@ class BatchServer:
             if self.prefix_cache and shareable:
                 for slot, req in zip(slot_arr, reqs):
                     self.pager.publish_prefix(int(slot), req.prompt)
-            if self.sync_timers:
-                # repro-lint: disable=R4 -- intentional sync: opt-in timer accuracy mode, off in serving runs
-                jax.block_until_ready(self.pages)
         else:
             self.cache = self._splice(self.cache, cache1, slot_arr,
                                       n_slots=self.slots)
@@ -635,12 +646,8 @@ class BatchServer:
                     # dense-SWA decode masked the entire prompt dead —
                     # caught by tests/test_differential.py
                     self.cache["pos"] = cache1["pos"]
-            if self.sync_timers:
-                # repro-lint: disable=R4 -- intentional sync: opt-in timer accuracy mode, off in serving runs
-                jax.block_until_ready(self.cache)
             for slot in slot_arr:
                 self.pager.admit(int(slot), self.table.active[int(slot)].pos)
-        self.stats["splice_wall_s"] += time.perf_counter() - tw
         self.stats["prefills"] += len(reqs)
         self.stats["admitted"] += len(reqs)
         self._tier_dirty = True                # fresh slots + page claims
@@ -726,8 +733,10 @@ class BatchServer:
                 # resident in shared pages — this is where the prefill
                 # compute is actually skipped
                 req.prefilled = hit
-                self.niccost.on_prefix_share(
-                    hit // self.pager.block_tokens, self.pager.block_bytes)
+                with self._span("niccost"):
+                    self.niccost.on_prefix_share(
+                        hit // self.pager.block_tokens,
+                        self.pager.block_bytes)
         else:
             self.pager.admit(req.slot, 0)
         req.to(RequestState.PREFILLING, now)
@@ -742,9 +751,10 @@ class BatchServer:
         self.stats["completed"] += 1
         self.completed_reqs.append(req)
         buf = encode_response(req.req_id, req.generated)
-        self.niccost.on_egress({1: req.req_id,
-                                2: np.asarray(req.generated,
-                                              np.int32).tobytes()})
+        with self._span("niccost"):
+            self.niccost.on_egress({1: req.req_id,
+                                    2: np.asarray(req.generated,
+                                                  np.int32).tobytes()})
         self._notify(req, buf)
         return buf
 
@@ -753,10 +763,11 @@ class BatchServer:
             (not self.continuous and req.pos >= self.max_len)
 
     def _harvest(self, now: float) -> List[bytes]:
-        out = [self._finish(req, now)
-               for _, req in sorted(self.active.items())
-               if req.state is RequestState.DECODE
-               and self._exhausted(req)]
+        with self._span("harvest"):
+            out = [self._finish(req, now)
+                   for _, req in sorted(self.active.items())
+                   if req.state is RequestState.DECODE
+                   and self._exhausted(req)]
         if out:
             self._tier_dirty = True            # slots released pages
         return out
@@ -778,41 +789,45 @@ class BatchServer:
             pre = {s: r for s, r in pre.items() if s in self._engaged}
         if not pre:
             return
-        step_v: Dict[int, int] = {}
-        hi = 0
-        for slot, req in pre.items():
-            v = min(self.prefill_chunk, len(req.prompt) - req.prefilled)
-            step_v[slot] = v
-            hi = max(hi, v)
-        C = next(b for b in self.chunk_buckets if b >= hi)
-        toks = np.zeros((self.slots, C), np.int32)
-        ctx = np.zeros((self.slots,), np.int32)
-        valid = np.zeros((self.slots,), np.int32)
-        for slot, req in pre.items():
-            v = step_v[slot]
-            toks[slot, :v] = req.prompt[req.prefilled:req.prefilled + v]
-            ctx[slot] = req.prefilled
-            valid[slot] = v
-            self.pager.advance(slot, req.prefilled + v)
-        # chunk growth may have force-demoted; land copies pre-dispatch
-        self._drain_migrations()
-        btab = self.pager.to_near(self._masked_block_table(pre))
-        completes = any(req.prefilled + step_v[slot] >= len(req.prompt)
-                        for slot, req in pre.items())
-        t0 = time.perf_counter()
-        logits, self.pages = self._chunk_prefill(
-            self.params, self.pages, jnp.asarray(toks), jnp.asarray(btab),
-            jnp.asarray(ctx), jnp.asarray(valid))
-        # materialize logits only on ticks where some prompt completes —
-        # a device sync on every chunk tick would serialize the async
-        # engine's dispatch overlap for nothing (mid-prompt logits are
-        # never read)
-        # repro-lint: disable=R4 -- intentional sync: gated on prompt completion; mid-chunk ticks stay async
-        nxt = np.asarray(logits).argmax(axis=-1) if completes else None
-        if self.sync_timers:
-            # repro-lint: disable=R4 -- intentional sync: opt-in timer accuracy mode, off in serving runs
-            jax.block_until_ready(self.pages)
-        self.stats["splice_wall_s"] += time.perf_counter() - t0
+        with self._span("chunk") as sp:
+            sp.stage("chunk.prep")
+            step_v: Dict[int, int] = {}
+            hi = 0
+            for slot, req in pre.items():
+                v = min(self.prefill_chunk, len(req.prompt) - req.prefilled)
+                step_v[slot] = v
+                hi = max(hi, v)
+            C = next(b for b in self.chunk_buckets if b >= hi)
+            toks = np.zeros((self.slots, C), np.int32)
+            ctx = np.zeros((self.slots,), np.int32)
+            valid = np.zeros((self.slots,), np.int32)
+            for slot, req in pre.items():
+                v = step_v[slot]
+                toks[slot, :v] = req.prompt[req.prefilled:req.prefilled + v]
+                ctx[slot] = req.prefilled
+                valid[slot] = v
+                self.pager.advance(slot, req.prefilled + v)
+            # chunk growth may have force-demoted; land copies pre-dispatch
+            self._drain_migrations()
+            btab = self.pager.to_near(self._masked_block_table(pre))
+            completes = any(req.prefilled + step_v[slot] >= len(req.prompt)
+                            for slot, req in pre.items())
+            sp.stage("chunk.dispatch")
+            logits, self.pages = self._chunk_prefill(
+                self.params, self.pages, jnp.asarray(toks),
+                jnp.asarray(btab), jnp.asarray(ctx), jnp.asarray(valid))
+            # materialize logits only on ticks where some prompt completes
+            # — a device sync on every chunk tick would serialize the async
+            # engine's dispatch overlap for nothing (mid-prompt logits are
+            # never read)
+            nxt = None
+            if completes:
+                sp.stage("chunk.wait")
+                # repro-lint: disable=R4 -- intentional sync: gated on prompt completion; mid-chunk ticks stay async
+                jax.block_until_ready(logits)
+                sp.stage("chunk.select")
+                # repro-lint: disable=R4 -- intentional sync: the completed prompts' first tokens reach host here
+                nxt = np.asarray(logits).argmax(axis=-1)
         self.stats["prefill_chunks"] += 1
         now = time.perf_counter()
         for slot, req in pre.items():
@@ -888,8 +903,9 @@ class BatchServer:
                 jnp.asarray(ds), jnp.asarray(dd),
                 jnp.asarray(ps), jnp.asarray(pd))
             if dem or pro:
-                self.niccost.on_kv_migrate(len(dem) + len(pro),
-                                           self.pager.block_bytes)
+                with self._span("niccost"):
+                    self.niccost.on_kv_migrate(len(dem) + len(pro),
+                                               self.pager.block_bytes)
                 self._tier_dirty = True        # residency moved
 
     def warmup_migrations(self):
@@ -993,34 +1009,37 @@ class BatchServer:
         """One scheduler tick: admit from queue, advance chunked prefills
         by one chunk, hand finished prefills to the decode worker (disagg
         only), one batched decode step over the DECODE slots."""
-        now = time.perf_counter()
-        self.stats["ticks"] += 1
-        if self.tiered:
-            # pins protect pages only within a tick; admission may demote
-            # last tick's working set (the plan below re-promotes)
-            self.pager.begin_tick(self.stats["ticks"])
-        if self.prefix_cache and self.prefix_watermark:
-            # proactive LRU eviction keeps free-page headroom for
-            # incoming admissions
-            self.pager.evict_to_watermark(self.prefix_watermark)
-        if self._unbilled_tickets:
-            self.niccost.on_ticket_batch(self._unbilled_tickets)
-            self._unbilled_tickets = 0
-        finished = self._admit(now)
-        self.stats["admit_wall_s"] += time.perf_counter() - now
-        # tiered plane: pick + promote this tick's engaged working set
-        # before any dispatch reads the arena (demand fetches land here)
-        self._engaged = self._plan_engaged()
-        if self.prefill_chunk:
-            self._prefill_step()
-        # disagg: move HANDOFF-parked requests into decode-worker slots
-        # before harvest, so an already-exhausted handoff (max_new == 1)
-        # finishes this same tick
-        self._do_handoffs(now)
-        # prefill emits the first token: single-token requests are already
-        # complete and must not burn a decode step
-        finished += self._harvest(now)
-        return finished + self._decode_tick(now)
+        with self._span("tick"):
+            with self._span("admit"):
+                now = time.perf_counter()
+                self.stats["ticks"] += 1
+                if self.tiered:
+                    # pins protect pages only within a tick; admission may
+                    # demote last tick's working set (the plan below
+                    # re-promotes)
+                    self.pager.begin_tick(self.stats["ticks"])
+                if self.prefix_cache and self.prefix_watermark:
+                    # proactive LRU eviction keeps free-page headroom for
+                    # incoming admissions
+                    self.pager.evict_to_watermark(self.prefix_watermark)
+                if self._unbilled_tickets:
+                    with self._span("niccost"):
+                        self.niccost.on_ticket_batch(self._unbilled_tickets)
+                    self._unbilled_tickets = 0
+                finished = self._admit(now)
+            # tiered plane: pick + promote this tick's engaged working set
+            # before any dispatch reads the arena (demand fetches land here)
+            self._engaged = self._plan_engaged()
+            if self.prefill_chunk:
+                self._prefill_step()
+            # disagg: move HANDOFF-parked requests into decode-worker slots
+            # before harvest, so an already-exhausted handoff (max_new == 1)
+            # finishes this same tick
+            self._do_handoffs(now)
+            # prefill emits the first token: single-token requests are
+            # already complete and must not burn a decode step
+            finished += self._harvest(now)
+            return finished + self._decode_tick(now)
 
     def _decode_tick(self, now: float) -> List[bytes]:
         """The decode worker's half of a tick: one batched decode dispatch
@@ -1042,35 +1061,45 @@ class BatchServer:
         last = np.zeros((self.slots, 1), np.int32)
         for slot, req in decoding.items():
             last[slot, 0] = req.generated[-1] if req.generated else 0
-        t0 = time.perf_counter()
-        if self.paged:
-            # per-slot ragged lengths; grow each slot's block list so the
-            # incoming token's page exists before the kernel computes its
-            # write location from (block_table, seq_lens)
-            lens = np.zeros((self.slots,), np.int32)
-            for slot, req in decoding.items():
-                lens[slot] = req.pos - 1          # tokens resident in pages
-                self.pager.advance(slot, req.pos)
-                if self.window:
-                    # pages wholly behind this (and every future) query's
-                    # window go back to the free list — steady-state
-                    # footprint stays O(window) per slot
-                    self.pager.release_behind(
-                        slot, max(0, req.pos - self.window))
-            nb = self._decode_bucket(int(lens.max()) + 1)
-            # token-growth allocations may have force-demoted cold pages
-            self._drain_migrations()
-            # PREFILLING slots hold live table rows but must be neither
-            # attended nor written by the decode step
-            btab = self.pager.to_near(self._masked_block_table(decoding, nb))
-            logits, self.pages = self._paged_decode(
-                self.params, self.pages, jnp.asarray(last),
-                jnp.asarray(btab), jnp.asarray(lens))
-        else:
-            logits, self.cache = self._decode(self.params, self.cache, last)
-        # repro-lint: disable=R4 -- intentional sync: greedy sampling needs the token on host to emit and schedule
-        nxt = np.asarray(logits).argmax(axis=-1)
-        self.stats["decode_wall_s"] += time.perf_counter() - t0
+        # decode_wall_s: prep + dispatch + wait + select, exactly
+        with self._span("decode") as sp:
+            sp.stage("decode.prep")
+            if self.paged:
+                # per-slot ragged lengths; grow each slot's block list so
+                # the incoming token's page exists before the kernel
+                # computes its write location from (block_table, seq_lens)
+                lens = np.zeros((self.slots,), np.int32)
+                for slot, req in decoding.items():
+                    lens[slot] = req.pos - 1      # tokens resident in pages
+                    self.pager.advance(slot, req.pos)
+                    if self.window:
+                        # pages wholly behind this (and every future)
+                        # query's window go back to the free list —
+                        # steady-state footprint stays O(window) per slot
+                        self.pager.release_behind(
+                            slot, max(0, req.pos - self.window))
+                nb = self._decode_bucket(int(lens.max()) + 1)
+                # token-growth allocations may have force-demoted cold
+                # pages
+                self._drain_migrations()
+                # PREFILLING slots hold live table rows but must be
+                # neither attended nor written by the decode step
+                btab = self.pager.to_near(
+                    self._masked_block_table(decoding, nb))
+                sp.stage("decode.dispatch")
+                logits, self.pages = self._paged_decode(
+                    self.params, self.pages, jnp.asarray(last),
+                    jnp.asarray(btab), jnp.asarray(lens))
+            else:
+                sp.stage("decode.dispatch")
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  last)
+            sp.stage("decode.wait")
+            # repro-lint: disable=R4 -- intentional sync: greedy sampling needs the token on host to emit and schedule
+            jax.block_until_ready(logits)
+            sp.stage("decode.select")
+            # repro-lint: disable=R4 -- intentional sync: the logits are ready; this copies them to host for the argmax
+            nxt = np.asarray(logits).argmax(axis=-1)
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += len(decoding)
 
@@ -1148,7 +1177,8 @@ class AsyncBatchServer(BatchServer):
             msg = wire.decode(buf, REQ_SCHEMA)
             rid = msg[1]
             self._check_unique(rid)
-            self.niccost.on_ingress(msg)
+            with self._span("niccost"):
+                self.niccost.on_ingress(msg)
             self.submit(self._request_from_msg(msg, len(buf)))
         else:
             rid = req.req_id
@@ -1318,16 +1348,19 @@ class DisaggEngine(BatchServer):
             self._unbilled_tickets += 1
             msg = self._handoff_msg(req, full_row[:span])
             buf = wire.encode(msg)
-            self.niccost.on_egress(msg)
+            with self._span("niccost"):
+                self.niccost.on_egress(msg)
             # decode worker: consume the message, bind in its own range,
             # map the same pool pages (zero KV bytes move)
             got = wire.decode(buf, HANDOFF_SCHEMA)
-            self.niccost.on_ingress(got)
+            with self._span("niccost"):
+                self.niccost.on_ingress(got)
             self.table.release(src)
             req.slot = self.prefill_slots + got[2] % self.decode_slots
             dst = self.table.bind(req, lo=self.prefill_slots, hi=self.slots)
             n_live = self.pager.handoff(src, dst)
-            self.niccost.on_kv_handoff(n_live, self.pager.block_bytes)
+            with self._span("niccost"):
+                self.niccost.on_kv_handoff(n_live, self.pager.block_bytes)
             new_row = np.asarray(self.pager.block_table()[dst])
             if _as_list(got.get(6, [])) != new_row[:span].tolist():
                 raise RuntimeError(
