@@ -460,7 +460,8 @@ class BatchServer:
         # counters, and the seconds of every host span (runtime.spans)
         self.stats = {"prefills": 0, "prefill_chunks": 0, "decode_steps": 0,
                       "completed": 0, "failed": 0, "admitted": 0, "ticks": 0,
-                      "decode_tokens": 0, **spans.zeroed()}
+                      "decode_tokens": 0, "decode_pages_shipped": 0,
+                      "decode_pages_live": 0, **spans.zeroed()}
         self.completed_reqs: List[Request] = []
         self._unbilled_tickets = 0
         self._busy_slot_ticks = 0
@@ -1079,6 +1080,14 @@ class BatchServer:
                         self.pager.release_behind(
                             slot, max(0, req.pos - self.window))
                 nb = self._decode_bucket(int(lens.max()) + 1)
+                # block-table columns shipped vs pages the kernel reads:
+                # those holding a resident token inside the window
+                bt = self.pager.block_tokens
+                first = (np.maximum(lens - self.window + 1, 0)
+                         if self.window else 0)
+                live = -(-lens // bt) - first // bt
+                self.stats["decode_pages_shipped"] += len(decoding) * nb
+                self.stats["decode_pages_live"] += int(live.sum())
                 # token-growth allocations may have force-demoted cold
                 # pages
                 self._drain_migrations()

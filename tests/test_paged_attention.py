@@ -71,6 +71,45 @@ def test_paged_kernel_bf16(
                                atol=2e-2, rtol=2e-2)
 
 
+# (H, K, hd) of mistral-nemo-12b and granite-moe-3b-a800m; at bt 16 the
+# derived compute block is 8 pages (nemo; granite in f32) or 16 (granite
+# bf16), so these cases span several blocks and end in a ragged one
+NEMO, GRANITE = (32, 8, 128), (24, 8, 64)
+F32_TOL, BF16_TOL = 1e-5, 2e-2
+
+
+@pytest.mark.parametrize("heads,dtype,nb,lens,window", [
+    (NEMO, jnp.float32, 11, [0, 128, 176], 0),        # nb % ppb != 0
+    (NEMO, jnp.float32, 20, [16, 127, 129, 256], 0),  # page / block edges
+    (NEMO, jnp.float32, 20, [0, 128, 257, 320], 100),  # window straddles
+    (GRANITE, jnp.float32, 20, [0, 200, 300], 128),   # window = one block
+    (GRANITE, jnp.float32, 20, [5, 129, 319], 1),     # only the new token
+    (NEMO, jnp.bfloat16, 21, [130, 333], 0),
+    (GRANITE, jnp.bfloat16, 21, [0, 257, 333], 0),
+    (GRANITE, jnp.bfloat16, 21, [256, 300, 40], 40),
+], ids=["nb-ragged", "edges", "window-straddle", "window-block",
+        "window-1", "nemo-bf16", "granite-bf16", "granite-bf16-window"])
+def test_paged_kernel_compute_blocks(heads, dtype, nb, lens, window):
+    """Multi-page compute blocks vs the oracle: a table whose width is not
+    a multiple of the block, lengths at page and block edges, empty slots,
+    windows that start inside a block, scattered (shuffled) page ids, and
+    the served models' head shapes in bf16."""
+    from repro.kernels.paged_attention import pages_per_block
+    H, K, hd = heads
+    bt = 16
+    ppb = pages_per_block(bt, K, hd, dtype)
+    assert nb > ppb and max(lens) <= nb * bt
+    q, kp, vp, kn, vn, btab, lens = _rand_pool(
+        len(lens), H, K, hd, bt, nb, dtype, lens=lens)
+    out = raw_paged(q, kp, vp, btab, lens, kn, vn, window=window,
+                    interpret=True)
+    exp = ref.paged_attention(q, kp, vp, btab, lens, kn, vn, window=window)
+    tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32),
+                               atol=tol, rtol=tol)
+
+
 def test_paged_ref_matches_dense_gqa():
     """The ref oracle itself must agree with the dense GQA attention the
     cache path uses: rebuild each slot's dense KV from its pages."""
@@ -442,6 +481,40 @@ class TestPagedServer:
         assert len(outs) == 4
         assert srv.stats["completed"] == 4
         assert srv.kv_stats()["paged"]["pages_in_use"] == 0
+
+    @pytest.mark.parametrize("arch", ["mistral-nemo-12b", "h2o-danube-3-4b"])
+    def test_decode_page_counters(self, arch):
+        """``decode_pages_shipped`` counts active slots x table columns of
+        every decode step; ``decode_pages_live`` the pages that hold a
+        resident token inside the window (what the kernel reads)."""
+        cfg, model = _tiny(arch, **F32)
+        params = model.init(jax.random.PRNGKey(3))
+        window = cfg.sliding_window
+        srv = BatchServer(model, batch_slots=3, max_len=64, params=params,
+                          nic_cost=None)
+        bt = srv.pager.block_tokens
+        calls = []
+        step = srv._paged_decode
+
+        def recording(params, pages, last, btab, lens):
+            calls.append((np.asarray(btab), np.asarray(lens)))
+            return step(params, pages, last, btab, lens)
+        srv._paged_decode = recording
+        prompts = [RNG.randint(1, 127, size=n).tolist()
+                   for n in (3, 17, 33, 9)]
+        _drain_tokens(srv, [(p, 12) for p in prompts])
+        assert calls
+        shipped = live = 0
+        for btab, lens in calls:
+            active = np.flatnonzero(lens > 0)
+            shipped += len(active) * btab.shape[1]
+            for L in lens[active]:
+                first = max(int(L) - window + 1, 0) if window else 0
+                live += sum(1 for g in range(btab.shape[1])
+                            if g * bt < L and (g + 1) * bt > first)
+        assert srv.stats["decode_pages_shipped"] == shipped
+        assert srv.stats["decode_pages_live"] == live
+        assert 0 < live < shipped
 
     def test_moe_family_paged(self):
         cfg, model = _tiny("qwen3-moe-235b-a22b", **F32)

@@ -1,5 +1,6 @@
 """The serving hot path compiles for a TPU v5e chip at mistral-nemo-12b
-widths, with no chip attached.
+widths (and the decode kernel at the benchmark cells' shapes), with no
+chip attached.
 
 The TPU compiler is installed with jax and compiles for a described
 ``v5e:2x2`` topology.  It refuses what interpret mode accepts: block shapes
@@ -12,7 +13,9 @@ it exits, so the topology is described inside a fixture (never at import)
 and every such compile lives in this one file.
 """
 import functools
+import importlib.util
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +83,42 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
     f = functools.partial(paged_attention, window=window, interpret=False)
     compiled = jax.jit(f).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _trace_kernels():
+    """The benchmark's kernel-name patterns (``chipbench/trace.py``)."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "chip", "chipbench", "trace.py")
+    spec = importlib.util.spec_from_file_location("_chipbench_trace", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod             # its dataclasses look it up
+    spec.loader.exec_module(mod)
+    return dict(mod.KERNELS)
+
+
+# the decode kernel at the benchmark cells' widest shapes: mistral-nemo-12b
+# (32 slots, 144-page tables) and granite-moe-3b-a800m (16 slots, 136 pages,
+# 24/8 heads of 64)
+@pytest.mark.parametrize("window", [0, 40])
+@pytest.mark.parametrize("b,nb,h,hd,pages", [(32, 144, 32, 128, 4609),
+                                             (16, 136, 24, 64, 2177)],
+                         ids=["nemo12", "granite"])
+def test_paged_decode_kernel_compiles_at_cell_shapes(
+        one_chip, no_persistent_cache, b, nb, h, hd, pages, window):
+    arena = _sds(one_chip, (pages, K, BT, hd))
+    args = (_sds(one_chip, (b, h, hd)), arena, arena,
+            _sds(one_chip, (b, nb), jnp.int32),
+            _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b, K, hd)),
+            _sds(one_chip, (b, K, hd)))
+    f = functools.partial(paged_attention, window=window, interpret=False)
+    text = jax.jit(f).lower(*args).compile().as_text()
+    # the trace finds the kernel by the HLO name of its custom call
+    names = [line.split(" = ")[0].split()[-1] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    kernels = _trace_kernels()
+    assert any(kernels["paged_attention"].search(n) for n in names), names
+    assert not any(kernels["paged_prefill_attention"].search(n)
+                   for n in names), names
 
 
 @pytest.mark.parametrize("window", [0, 40])
